@@ -344,10 +344,11 @@ class DeviceSet:
             idx.padded_vals, ((0, 0), (0, gmax - idx.gmax)),
             constant_values=np.uint32(0xFFFFFFFF),
         )
-        vals = jax.lax.bitcast_convert_type(jnp.asarray(padded), jnp.int32)
+        # reinterpret on the host: one transfer per array, no device op
         return cls(
             t=idx.t, gmax=gmax, m=idx.family.m, w=idx.w, n=idx.n,
-            vals=vals, images=jnp.asarray(idx.images),
+            vals=jnp.asarray(padded.view(np.int32)),
+            images=jnp.asarray(idx.images),
         )
 
     def shardable(self, n_shards: int) -> bool:
@@ -528,7 +529,7 @@ def _intersect_k_batch(
     # survivor indices, G-filled past the end — identical to
     # nonzero(size=capacity, fill_value=G) but trivially batched.
     pos = jnp.where(passed, jnp.arange(G, dtype=jnp.int32)[None, :], G)
-    surv = jnp.sort(pos, axis=1)
+    surv = setops.sort_rows(pos)
     if capacity <= G:
         surv = surv[:, :capacity]
     else:
@@ -974,15 +975,12 @@ def clear_exec_jit_cache() -> None:
     deterministic regardless of what earlier tests compiled (the jit cache
     is process-global).  Clears the sharded pipeline's cache too — the 2-D
     pipeline's row executables live in the same two jits (keyed apart by
-    their ``trace_counter``), so they are covered.  No-op if the jax
-    version lacks ``clear_cache``.
+    their ``trace_counter``), so they are covered.
     """
     for fn in (_intersect_k_batch, _intersect_k_sharded_batch,
                _eval_expr_batch, _eval_expr_sharded_batch,
                _intersect_count_batch, _intersect_count_sharded_batch):
-        clear = getattr(fn, "clear_cache", None)
-        if clear is not None:
-            clear()
+        fn.clear_cache()
 
 
 # --------------------------------------------------------------------------
@@ -1050,7 +1048,7 @@ def _local_shard_block(lvals, limages, ts, capacity_per_shard, use_pallas):
     # plain slice always suffices (no pad branch, unlike the unsharded
     # pipeline where capacity may exceed G)
     assert capacity_per_shard <= G_local, "caller must clamp to local G"
-    surv = jnp.sort(pos, axis=1)[:, :capacity_per_shard]
+    surv = setops.sort_rows(pos)[:, :capacity_per_shard]
     valid_row = surv < G_local
     surv_c = jnp.minimum(surv, G_local - 1)
     rows = jnp.arange(B)[:, None]
@@ -1114,12 +1112,10 @@ def _intersect_k_sharded_batch(
         # can concatenate them into (n_shards, B) without communication
         return packed, r[None], n_surv[None], overflow[None]
 
-    from jax.experimental.shard_map import shard_map
-
     in_specs = tuple([P(None, axis)] * (2 * k))
     out_specs = (P(None, axis), P(axis), P(axis), P(axis))
-    fn = shard_map(local_fn, mesh=mesh, in_specs=in_specs,
-                   out_specs=out_specs, check_rep=False)
+    fn = jax.shard_map(local_fn, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     return fn(*vals, *images)
 
 
@@ -1595,12 +1591,10 @@ def _intersect_count_sharded_batch(
         # count matrices into (n_shards, B, C) without communication
         return _count_block(lpv, lcv, ts, use_pallas)[None]
 
-    from jax.experimental.shard_map import shard_map
-
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(None, axis), P(None, None, axis)),
-        out_specs=P(axis), check_rep=False,
+        out_specs=P(axis), check_vma=False,
     )
     counts = fn(pv, cv).sum(axis=0)                       # (B, C)
     C = cv.shape[1]
@@ -2044,13 +2038,11 @@ def _eval_expr_sharded_batch(
             lvals, eshape, capacity_per_shard)
         return root, r[None], max_count[None], overflow[None], subs
 
-    from jax.experimental.shard_map import shard_map
-
     in_specs = tuple([P(None, axis)] * len(ts))
     out_specs = (P(None, axis), P(axis), P(axis), P(axis),
                  tuple([P(None, axis)] * n_subs))
-    fn = shard_map(local_fn, mesh=mesh, in_specs=in_specs,
-                   out_specs=out_specs, check_rep=False)
+    fn = jax.shard_map(local_fn, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     return fn(*vals)
 
 

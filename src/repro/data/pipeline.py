@@ -56,27 +56,46 @@ class SyntheticLMData:
             step += 1
 
 
+# documents per vectorized block of :func:`zipf_corpus`: bounds the
+# block's (doc, term) key array while keeping per-block overhead small
+_CORPUS_BLOCK_DOCS = 1 << 16
+
+
 def zipf_corpus(n_docs: int, vocab: int = 50000, mean_len: int = 200,
                 alpha: float = 1.2, seed: int = 0) -> List[np.ndarray]:
     """Documents as arrays of term-ids with a Zipf unigram distribution —
     produces realistically skewed posting-list lengths for the search
-    engine (frequent terms -> long lists, as in the paper's Bing data)."""
+    engine (frequent terms -> long lists, as in the paper's Bing data).
+
+    Each document draws ``Poisson(mean_len)`` (at least 8) Zipf terms,
+    folded into ``[0, vocab)`` and deduplicated into a sorted array.  The
+    draws come in blocks of documents, one ``rng.zipf`` call per block;
+    the generator's stream is the same as one call per document, so the
+    corpus depends only on the arguments."""
     rng = np.random.default_rng(seed)
-    docs = []
     lengths = rng.poisson(mean_len, size=n_docs).clip(min=8)
-    for i in range(n_docs):
-        terms = rng.zipf(alpha, size=lengths[i])
-        docs.append(np.unique((terms - 1) % vocab).astype(np.uint32))
+    docs: List[np.ndarray] = []
+    for lo in range(0, n_docs, _CORPUS_BLOCK_DOCS):
+        lens = lengths[lo:lo + _CORPUS_BLOCK_DOCS]
+        terms = (rng.zipf(alpha, size=int(lens.sum())) - 1) % vocab
+        doc = np.repeat(np.arange(len(lens), dtype=np.int64), lens)
+        key = np.unique(doc * vocab + terms)   # sorted by (doc, term), deduped
+        bounds = np.searchsorted(key, np.arange(1, len(lens)) * vocab)
+        docs.extend(np.split((key % vocab).astype(np.uint32), bounds))
     return docs
 
 
 def inverted_index(docs: Sequence[np.ndarray]) -> Dict[int, np.ndarray]:
-    """term -> sorted array of doc ids."""
-    from collections import defaultdict
-
-    post = defaultdict(list)
-    for doc_id, terms in enumerate(docs):
-        for t in terms.tolist():
-            post[t].append(doc_id)
-    return {t: np.asarray(sorted(ids), dtype=np.uint32)
-            for t, ids in post.items()}
+    """term -> sorted array of doc ids, keyed in order of each term's
+    first appearance."""
+    lens = np.fromiter((len(d) for d in docs), dtype=np.int64, count=len(docs))
+    terms = (np.concatenate(docs) if len(docs)
+             else np.empty(0, dtype=np.uint32))
+    doc_ids = np.repeat(np.arange(len(docs), dtype=np.uint32), lens)
+    order = np.argsort(terms, kind="stable")   # doc ids stay ascending per term
+    by_term = terms[order]
+    starts = np.flatnonzero(np.diff(by_term, prepend=-1))
+    uniq = by_term[starts]
+    lists = np.split(doc_ids[order], starts[1:])
+    first_seen = np.argsort(order[starts], kind="stable")
+    return {int(uniq[i]): lists[i] for i in first_seen}

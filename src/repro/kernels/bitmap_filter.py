@@ -41,9 +41,10 @@ def _filter_kernel(imgs_ref, out_ref, *, k: int, m: int, wp: int):
     for i in range(1, k):                      # k is tiny & static: unroll
         h = h & imgs_ref[0, i]                 # (F, 128) VPU AND
     hw = h.reshape(m, wp, LANES)               # split images from words
-    nonzero = (hw != 0).max(axis=1)            # OR over words -> (m, 128)
+    # int32 0/1 flags: Mosaic does not reduce bool arrays
+    nonzero = (hw != 0).astype(jnp.int32).max(axis=1)  # OR over words: (m, 128)
     passed = nonzero.min(axis=0)               # AND over images -> (128,)
-    out_ref[...] = jnp.broadcast_to(passed.astype(jnp.int32), (1, SUBLANES, LANES))
+    out_ref[...] = jnp.broadcast_to(passed, (1, SUBLANES, LANES))
 
 
 def _pack(images: jnp.ndarray):
@@ -61,7 +62,7 @@ def _pack(images: jnp.ndarray):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def bitmap_filter_pallas(images: jnp.ndarray, *, interpret: bool = True) -> jnp.ndarray:
+def bitmap_filter_pallas(images: jnp.ndarray, *, interpret: bool) -> jnp.ndarray:
     """Survivor mask for (k, G, m, W) or (B, k, G, m, W) group-tuple images.
 
     Returns (G,) / (B, G) bool — see kernels.ref.bitmap_filter_ref for

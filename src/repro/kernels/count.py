@@ -52,10 +52,10 @@ def _count_kernel(a_ref, b_ref, out_ref):
     """a_ref: (8, gap) int32; b_ref: (8, gbp) int32; out_ref: (8, LANES) int32."""
     a = a_ref[...]
     b = b_ref[...]
-    eq = a[:, :, None] == b[:, None, :]          # (8, gap, gbp)
+    # int32 0/1 flags throughout: Mosaic does not reduce bool arrays
+    eq = (a[:, :, None] == b[:, None, :]).astype(jnp.int32)  # (8, gap, gbp)
     hit = eq.max(axis=2)                          # any over b -> (8, gap)
-    real = a != SENTINEL
-    cnt = (hit & real).astype(jnp.int32).sum(axis=1)  # (8,)
+    cnt = jnp.where(a != SENTINEL, hit, 0).sum(axis=1)  # (8,)
     out_ref[...] = jnp.broadcast_to(cnt[:, None], out_ref.shape)
 
 
@@ -68,7 +68,7 @@ def _pad_lanes(x: jnp.ndarray, fill) -> jnp.ndarray:
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def pair_count_pallas(a_vals: jnp.ndarray, b_vals: jnp.ndarray, *,
-                      interpret: bool = True) -> jnp.ndarray:
+                      interpret: bool) -> jnp.ndarray:
     """(S, ga) x (S, gb) sentinel-padded int32 -> (S,) int32 match counts.
 
     Leading batch axes fold into the row grid exactly as in

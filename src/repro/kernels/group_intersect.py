@@ -29,10 +29,10 @@ def _match_kernel(a_ref, b_ref, out_ref):
     """a_ref: (8, gap) int32; b_ref: (8, gbp) int32; out_ref: (8, gap) int32."""
     a = a_ref[...]
     b = b_ref[...]
-    eq = a[:, :, None] == b[:, None, :]          # (8, gap, gbp)
+    # int32 0/1 flags throughout: Mosaic does not reduce bool arrays
+    eq = (a[:, :, None] == b[:, None, :]).astype(jnp.int32)  # (8, gap, gbp)
     hit = eq.max(axis=2)                          # any over b -> (8, gap)
-    real = a != SENTINEL
-    out_ref[...] = (hit & real).astype(jnp.int32)
+    out_ref[...] = jnp.where(a != SENTINEL, hit, 0)
 
 
 def _pad_lanes(x: jnp.ndarray, fill) -> jnp.ndarray:
@@ -44,7 +44,7 @@ def _pad_lanes(x: jnp.ndarray, fill) -> jnp.ndarray:
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def group_match_pallas(a_vals: jnp.ndarray, b_vals: jnp.ndarray, *,
-                       interpret: bool = True) -> jnp.ndarray:
+                       interpret: bool) -> jnp.ndarray:
     """(S, ga) x (S, gb) sentinel-padded int32 -> (S, ga) bool membership.
 
     A leading batch axis ((B, S, ga) x (B, S, gb) -> (B, S, ga)) folds into

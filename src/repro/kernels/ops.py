@@ -1,8 +1,7 @@
 """Public jit'd wrappers over the Pallas kernels (with pure-jnp fallback).
 
 ``use_pallas`` selects the execution path:
-  * "auto"   — Pallas compiled on TPU, Pallas interpret=True elsewhere for
-               kernel-path fidelity in tests, unless the problem is tiny.
+  * "auto"   — Pallas compiled on TPU, the pure-jnp reference elsewhere.
   * True     — always Pallas (interpret on non-TPU backends).
   * False    — pure-jnp reference (ref.py) — same semantics, used for
                oracle checks and for CPU-speed benchmarks where the python

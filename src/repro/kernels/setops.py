@@ -16,7 +16,7 @@ sort.
 
 Why no hand-written Pallas here: unlike ``bitmap_filter`` /
 ``group_match`` (bit-twiddling the XLA fuser won't invent), these passes
-are dominated by *sorting*, and ``jnp.sort`` already lowers to the
+are dominated by *sorting*, and ``sort_rows`` already lowers to the
 backend's tuned sort (TPU sort HLO / CUB on GPU).  A Pallas bitonic
 network would re-implement that slower.  The passes still run inside the
 same jit'd, bucketed ``(B, …)`` executables as the kernels, so they
@@ -36,7 +36,7 @@ import jax.numpy as jnp
 import numpy as np
 
 __all__ = [
-    "SENTINEL", "densify", "member_mask", "union_pass", "diff_pass",
+    "SENTINEL", "sort_rows", "densify", "member_mask", "union_pass", "diff_pass",
     "intersect_pass", "densify_ref", "union_ref", "diff_ref",
     "intersect_ref",
 ]
@@ -47,12 +47,22 @@ __all__ = [
 SENTINEL = np.uint32(0xFFFFFFFF)
 
 
+def sort_rows(x: jnp.ndarray) -> jnp.ndarray:
+    """Ascending sort along the last axis.
+
+    Every device sort in this repo sorts one operand of plain integers, so
+    stability cannot change its output; the unstable sort is the one the
+    TPU compiler builds quickly (about 1.7 s against 9 s for an (8, 4096)
+    int32 row sort, compiling for a TPU v5e).  ``jnp.sort`` is stable."""
+    return jax.lax.sort(x, dimension=x.ndim - 1, is_stable=False)
+
+
 def densify(vals: jnp.ndarray) -> jnp.ndarray:
     """(B, 2^t, gmax) int32 device-set values (uint32 bitcast, -1 padded)
     -> (B, 2^t * gmax) sorted uint32 dense rows, sentinel-padded.  The
     -1 padding bitcasts to the sentinel, which sorts last."""
     u = jax.lax.bitcast_convert_type(vals, jnp.uint32)
-    return jnp.sort(u.reshape(u.shape[0], -1), axis=1)
+    return sort_rows(u.reshape(u.shape[0], -1))
 
 
 def member_mask(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
@@ -70,13 +80,13 @@ def union_pass(bufs: Sequence[jnp.ndarray], width: int
     """n-ary ∪ of sorted sentinel-padded rows -> (out (B, width) sorted,
     count (B,) int32 = true union size).  concat → sort → adjacent-dup
     mask → re-sort → slice; ``count > width`` means truncation."""
-    cat = jnp.sort(jnp.concatenate(list(bufs), axis=1), axis=1)
+    cat = sort_rows(jnp.concatenate(list(bufs), axis=1))
     dup = jnp.concatenate(
         [jnp.zeros_like(cat[:, :1], dtype=bool), cat[:, 1:] == cat[:, :-1]],
         axis=1)
     uniq = jnp.where(dup, SENTINEL, cat)
     count = jnp.sum(uniq != SENTINEL, axis=1, dtype=jnp.int32)
-    return jnp.sort(uniq, axis=1)[:, :width], count
+    return sort_rows(uniq)[:, :width], count
 
 
 def diff_pass(a: jnp.ndarray, b: jnp.ndarray, width: int
@@ -85,7 +95,7 @@ def diff_pass(a: jnp.ndarray, b: jnp.ndarray, width: int
     (B,) int32).  Both inputs sorted sentinel-padded rows."""
     out = jnp.where(member_mask(a, b), SENTINEL, a)
     count = jnp.sum(out != SENTINEL, axis=1, dtype=jnp.int32)
-    return jnp.sort(out, axis=1)[:, :width], count
+    return sort_rows(out)[:, :width], count
 
 
 def intersect_pass(bufs: Sequence[jnp.ndarray], width: int
@@ -96,7 +106,7 @@ def intersect_pass(bufs: Sequence[jnp.ndarray], width: int
     for b in bufs[1:]:
         acc = jnp.where(member_mask(acc, b), acc, SENTINEL)
     count = jnp.sum(acc != SENTINEL, axis=1, dtype=jnp.int32)
-    return jnp.sort(acc, axis=1)[:, :width], count
+    return sort_rows(acc)[:, :width], count
 
 
 # ---------------------------------------------------------------------------

@@ -279,7 +279,6 @@ def _attention_decode_flash(p, spec, x, cache_k, cache_v, pos, window, mesh):
     each shard computes masked partial softmax stats; two psums of
     (B, H)-sized stats produce the exact softmax.  The token's new K/V is
     written only by the owning shard."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     b = x.shape[0]
@@ -330,14 +329,14 @@ def _attention_decode_flash(p, spec, x, cache_k, cache_v, pos, window, mesh):
         return o, ck_l, cv_l
 
     dps = dp if dp else None
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(dps, None, None, None), P(dps, None, None, None),
                   P(dps, None, None, None), P(dps, "model", None, None),
                   P(dps, "model", None, None)),
         out_specs=(P(dps, None, None, None), P(dps, "model", None, None),
                    P(dps, "model", None, None)),
-        check_rep=False,
+        check_vma=False,
     )
     o, cache_k, cache_v = fn(q, k_new, v_new, cache_k, cache_v)
     o = o.reshape(b, 1, spec.n_heads, spec.head_dim)

@@ -138,7 +138,6 @@ def _moe_ffn_shardmap(p: Params, cfg: ArchConfig, x: jnp.ndarray, mesh
     the slots of its own E/M experts, runs the expert SwiGLU locally, and
     reverses the exchange.  FSDP-sharded expert weights are all-gathered at
     entry by shard_map's in_specs (ZeRO-3 semantics)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     b, s, d = x.shape
@@ -217,12 +216,12 @@ def _moe_ffn_shardmap(p: Params, cfg: ArchConfig, x: jnp.ndarray, mesh
             contrib = jax.lax.all_gather(contrib, "model", axis=0, tiled=True)
         return contrib.reshape(xl.shape), aux
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(dp if dp else None, None, None), P(), P("model",),
                   P("model",), P("model",)),
         out_specs=(P(dp if dp else None, None, None), P()),
-        check_rep=False,
+        check_vma=False,
     )
     out, aux = fn(x, p["router"].astype(jnp.float32),
                   p["w_gate"], p["w_up"], p["w_down"])
