@@ -38,7 +38,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parent / "src"))
 import jax  # noqa: E402
 import numpy as np  # noqa: E402
 
-from repro.compile_cache import enable_compile_cache  # noqa: E402
+from repro.compile_cache import configure_compile_cache  # noqa: E402
 from repro.core.engine import (  # noqa: E402
     EXEC_COUNTERS, bucket_hlo_text, pow2_tiers,
 )
@@ -88,8 +88,10 @@ ONE_CHIP = Config(docs=1_000_000, min_index_bytes=10**9)
 # smaller corpus, wider band: lists past 32768 postings reach 2^12 groups,
 # the shard_min_g threshold at which the planner routes to the 2-D mesh;
 # term picks spread further so that smaller lists make single-device
-# buckets for the replica balancer too
-FOUR_CHIPS = Config(docs=200_000, band_hi=0.2, conj_pool=24,
+# buckets for the replica balancer too.  A vocabulary of 10,000 keeps the
+# same long lists but builds two engines' mirrors ten times faster than
+# 100,000 terms, which took most of a four-chip run.
+FOUR_CHIPS = Config(docs=200_000, vocab=10_000, band_hi=0.2, conj_pool=24,
                     conj_queries=96, pareto_scale=100.0, expr_pool=4,
                     expr_queries=8, warm_sigs=4)
 
@@ -124,6 +126,11 @@ def draw_traffic(cfg: Config, terms, seed: int):
             e = f"({q[0]}|{q[1]})&{q[2]}" + (f"-{q[3]}" if len(q) > 3 else "")
             pool.append(e)
     exprs = [pool[i] for i in rng.integers(0, len(pool), cfg.expr_queries)]
+    distinct = {tuple(q) for q in conj}
+    by_k = {k: sum(len(q) == k for q in distinct) for k in (1, 2, 3, 4)}
+    print(f"traffic: {len(conj)} conjunctions ({len(distinct)} distinct, "
+          f"by term count {by_k}), {len(exprs)} expressions "
+          f"({len(set(exprs))} distinct)")
     mixed = conj + exprs
     return conj, exprs, [mixed[i] for i in rng.permutation(len(mixed))]
 
@@ -235,6 +242,11 @@ def suggest_phase(postings: dict, cfg: Config) -> None:
     check(routes.get("suggest/device", 0) > 0, "no suggest call on device")
 
 
+def print_counters(label: str, counters: dict, keys) -> None:
+    print(f"{label} counters: " + ", ".join(
+        f"{k} {counters[k]}" for k in keys))
+
+
 def serve_one_chip(cfg: Config, seed: int) -> None:
     postings = build_postings(cfg, seed)
     t0 = time.perf_counter()
@@ -251,10 +263,9 @@ def serve_one_chip(cfg: Config, seed: int) -> None:
     EXEC_COUNTERS.reset()
     results = serve(engine, queries)
     routes = check_answers(postings, queries, results, "queries")
-    print("counters: " + ", ".join(
-        f"{k} {EXEC_COUNTERS[k]}" for k in (
-            "batch_calls", "batch_traces", "rerun_calls", "expr_calls",
-            "expr_traces", "expr_rerun_calls", "result_cache_hits")))
+    print_counters("serving", EXEC_COUNTERS.snapshot(), (
+        "batch_calls", "batch_traces", "rerun_calls", "expr_calls",
+        "expr_traces", "expr_rerun_calls", "result_cache_hits"))
     check(routes.get("rangroupscan/device", 0) > 0,
           "no conjunctive query served on the device")
     check(routes.get("expr/device", 0) > 0,
@@ -304,7 +315,11 @@ def serve_four_chips(cfg: Config, seed: int) -> None:
     EXEC_COUNTERS.reset()
     mesh_results = serve(mesh_engine, queries)
     snap = EXEC_COUNTERS.snapshot()
+    EXEC_COUNTERS.reset()
     one_results = serve(one_engine, queries)
+    traces = ("batch_traces", "mesh2d_traces", "expr_traces")
+    print_counters("2x2 serving", snap, traces)
+    print_counters("one-chip serving", EXEC_COUNTERS.snapshot(), traces)
     routes = check_answers(postings, queries, mesh_results, "2x2 topology")
     check_answers(postings, queries, one_results, "one chip")
     differ = sum(not np.array_equal(a.doc_ids, b.doc_ids)
@@ -332,7 +347,7 @@ def main() -> None:
     check(len(devices) >= args.chips,
           f"{args.chips} chips asked for, {len(devices)} found")
     print(f"devices: {len(devices)} x {devices[0].device_kind}")
-    cache = enable_compile_cache(multi_device=args.chips > 1)
+    cache = configure_compile_cache(multi_device=args.chips > 1)
     print(f"compile cache: {cache or 'off (programs span several devices)'}")
     t0 = time.perf_counter()
     if args.chips == 4:

@@ -33,7 +33,7 @@ import time
 
 import numpy as np
 
-from repro.compile_cache import enable_compile_cache
+from repro.compile_cache import configure_compile_cache
 from repro.data.pipeline import inverted_index, zipf_corpus
 from repro.serve.search import AsyncSearchEngine, SearchEngine, zipf_query_log
 
@@ -146,7 +146,7 @@ def main():
                          "print the metrics exposition (and a span-tree "
                          "sample) after the run")
     args = ap.parse_args()
-    enable_compile_cache(multi_device=bool(args.mesh))
+    configure_compile_cache(multi_device=bool(args.mesh))
 
     topology = None
     if args.mesh:

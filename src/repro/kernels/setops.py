@@ -52,8 +52,9 @@ def sort_rows(x: jnp.ndarray) -> jnp.ndarray:
 
     Every device sort in this repo sorts one operand of plain integers, so
     stability cannot change its output; the unstable sort is the one the
-    TPU compiler builds quickly (about 1.7 s against 9 s for an (8, 4096)
-    int32 row sort, compiling for a TPU v5e).  ``jnp.sort`` is stable."""
+    TPU compiler builds quickly (compile times for a TPU v5e are in
+    ``PERF.md``; its run time on the chip is not measured).  ``jnp.sort``
+    is stable."""
     return jax.lax.sort(x, dimension=x.ndim - 1, is_stable=False)
 
 

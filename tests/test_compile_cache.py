@@ -1,4 +1,4 @@
-"""Where ``enable_compile_cache`` puts JAX's persistent compile cache.
+"""Where ``configure_compile_cache`` puts JAX's persistent compile cache.
 
 JAX decides once per process whether the cache is used, so each test
 resets that decision before and after, and restores the settings it
@@ -45,7 +45,7 @@ def _written(d):
 
 def test_cache_defaults_to_the_checkout(dirs):
     env_dir, default_dir = dirs
-    assert compile_cache.enable_compile_cache() == str(default_dir)
+    assert compile_cache.configure_compile_cache() == str(default_dir)
     _compile_something()
     assert _written(default_dir)
     assert not _written(env_dir)
@@ -56,7 +56,7 @@ def test_environment_directory_stands(dirs, monkeypatch):
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(env_dir))
     # what JAX does with the variable when it is imported
     jax.config.update("jax_compilation_cache_dir", str(env_dir))
-    assert compile_cache.enable_compile_cache() == str(env_dir)
+    assert compile_cache.configure_compile_cache() == str(env_dir)
     assert jax.config.jax_compilation_cache_dir == str(env_dir)
     _compile_something()
     assert _written(env_dir)
@@ -69,7 +69,7 @@ def test_multi_device_process_keeps_no_cache(dirs, monkeypatch, env_set):
     if env_set:
         monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(env_dir))
         jax.config.update("jax_compilation_cache_dir", str(env_dir))
-    assert compile_cache.enable_compile_cache(multi_device=True) is None
+    assert compile_cache.configure_compile_cache(multi_device=True) is None
     _compile_something()
     assert not _written(env_dir)
     assert not _written(default_dir)

@@ -36,18 +36,20 @@ def v5e():
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
 
-    os.environ.setdefault("TPU_LOG_DIR", "disabled")
-    try:
-        topo = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-    except Exception as e:
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    yield topo.devices
-    jax.config.update("jax_enable_compilation_cache", was)
-    compilation_cache.reset_cache()
+    with pytest.MonkeyPatch.context() as mp:
+        if "TPU_LOG_DIR" not in os.environ:
+            mp.setenv("TPU_LOG_DIR", "disabled")
+        try:
+            topo = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        was = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        yield topo.devices
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
 
 
 @pytest.fixture(scope="module")
