@@ -83,7 +83,9 @@ from __future__ import annotations
 import dataclasses
 import functools
 import threading
-from typing import Callable, Dict, List, Optional, Sequence, Tuple
+import time
+from typing import (Callable, Dict, List, NamedTuple, Optional, Sequence,
+                    Tuple)
 
 import jax
 import jax.numpy as jnp
@@ -101,6 +103,7 @@ __all__ = [
     "SHARD_MIN_G",
     "default_capacity",
     "default_capacity_per_shard",
+    "PassRecord",
     "PendingBatch",
     "dispatch_device_batch",
     "dispatch_mesh2d_batch",
@@ -554,6 +557,32 @@ def _signature(sets: Sequence[DeviceSet]) -> Tuple[Tuple[int, ...], Tuple[int, .
     return tuple(s.t for s in sets), tuple(s.gmax for s in sets)
 
 
+class PassRecord(NamedTuple):
+    """One device pass of a bucket, on the ``time.perf_counter`` clock:
+    pass number (0 the first, 1 the overflow re-run), the query rows it
+    ran, its survivor capacity, when it was issued, and when its blocking
+    fetch began and ended.  The exec layer turns these into spans."""
+
+    pass_no: int
+    rows: int
+    capacity: int
+    t_issue: float
+    t_fetch: float
+    t_fetched: float
+
+
+def _fetch_pass(passes: List[PassRecord], handles, rows: int, capacity: int,
+                t_issue: float):
+    """Block for one pass's device buffers (``jax.device_get``) and append
+    its :class:`PassRecord` to ``passes``; every collect loop fetches
+    through here."""
+    t_fetch = time.perf_counter()
+    fetched = jax.device_get(handles)
+    passes.append(PassRecord(len(passes), rows, capacity, t_issue, t_fetch,
+                             time.perf_counter()))
+    return fetched
+
+
 @dataclasses.dataclass
 class PendingBatch:
     """In-flight handle for one dispatched bucket pass.
@@ -572,12 +601,17 @@ class PendingBatch:
     polls it without blocking (a non-blocking peek for schedulers that
     want to collect completed buckets first).  :meth:`collect` is
     memoized — calling it twice returns the same result list.
+
+    ``passes`` fills as :meth:`collect` fetches: one :class:`PassRecord`
+    per device pass, so a caller can tell the first pass's transfer from
+    an overflow re-run's issue and transfer.
     """
 
     n_queries: int
     handles: object = None
     _collect: Optional[Callable[[], List[Tuple[np.ndarray, Dict]]]] = None
     _results: Optional[List[Tuple[np.ndarray, Dict]]] = None
+    passes: List[PassRecord] = dataclasses.field(default_factory=list)
 
     def is_ready(self) -> bool:
         """True when every first-pass device buffer has materialized (a
@@ -641,13 +675,17 @@ def dispatch_device_batch(
 
     first_active = list(range(len(ordered)))
     first_cap = capacity or default_capacity(ts)
+    passes: List[PassRecord] = []
+    first_issue = time.perf_counter()
     first_handles = issue(first_active, first_cap)
 
     def collect() -> List[Tuple[np.ndarray, Dict]]:
         results: List[Optional[Tuple[np.ndarray, Dict]]] = [None] * len(ordered)
         active, cap, handles = first_active, first_cap, first_handles
+        t_issue = first_issue
         while True:
-            packed_h, r_h, n_surv_h, over_h = jax.device_get(handles)
+            packed_h, r_h, n_surv_h, over_h = _fetch_pass(
+                passes, handles, len(active), cap, t_issue)
             rerun = []
             for row, qi in enumerate(active):
                 if over_h[row]:
@@ -670,10 +708,11 @@ def dispatch_device_batch(
             active = rerun
             cap = G  # rare path: ONE re-run of the overflow subset at G
             EXEC_COUNTERS["rerun_calls"] += 1
+            t_issue = time.perf_counter()
             handles = issue(active, cap)
 
     return PendingBatch(n_queries=len(ordered), handles=first_handles,
-                        _collect=collect)
+                        _collect=collect, passes=passes)
 
 
 def intersect_device_batch(
@@ -1165,13 +1204,17 @@ def dispatch_sharded_batch(
         capacity_per_shard or default_capacity_per_shard(ts, n_shards),
         G_local,
     )
+    passes: List[PassRecord] = []
+    first_issue = time.perf_counter()
     first_handles = issue(first_active, first_cap)
 
     def collect() -> List[Tuple[np.ndarray, Dict]]:
         results: List[Optional[Tuple[np.ndarray, Dict]]] = [None] * len(ordered)
         active, cap, handles = first_active, first_cap, first_handles
+        t_issue = first_issue
         while True:
-            packed_h, r_h, n_surv_h, over_h = jax.device_get(handles)
+            packed_h, r_h, n_surv_h, over_h = _fetch_pass(
+                passes, handles, len(active), cap, t_issue)
             rerun = []
             for row, qi in enumerate(active):
                 if over_h[:, row].any():
@@ -1196,10 +1239,11 @@ def dispatch_sharded_batch(
             active = rerun
             cap = G_local  # rare path: one re-run at local G, no overflow
             EXEC_COUNTERS["sharded_rerun_calls"] += 1
+            t_issue = time.perf_counter()
             handles = issue(active, cap)
 
     return PendingBatch(n_queries=len(ordered), handles=first_handles,
-                        _collect=collect)
+                        _collect=collect, passes=passes)
 
 
 def intersect_sharded_batch(
@@ -1340,16 +1384,20 @@ def dispatch_mesh2d_batch(
         capacity_per_shard or default_capacity_per_shard(ts, n_shards),
         G_local,
     )
+    passes: List[PassRecord] = []
+    first_issue = time.perf_counter()
     first_handles, first_slice_len = issue(first_active, first_cap)
 
     def collect() -> List[Tuple[np.ndarray, Dict]]:
         results: List[Optional[Tuple[np.ndarray, Dict]]] = [None] * len(ordered)
         active, cap = first_active, first_cap
+        t_issue = first_issue
         handles, slice_len = first_handles, first_slice_len
         while True:
             # one collection point: every row was in flight before any
             # transfer started
-            fetched = jax.device_get(handles)
+            fetched = _fetch_pass(passes, handles, len(active), cap,
+                                  t_issue)
             rerun = []
             for rr, (packed_h, r_h, n_surv_h, over_h) in fetched.items():
                 chunk_start = rr * slice_len
@@ -1383,10 +1431,11 @@ def dispatch_mesh2d_batch(
             active = rerun
             cap = G_local  # rare path: one re-run at local G, no overflow
             EXEC_COUNTERS["mesh2d_rerun_calls"] += 1
+            t_issue = time.perf_counter()
             handles, slice_len = issue(active, cap)
 
     return PendingBatch(n_queries=len(ordered), handles=first_handles,
-                        _collect=collect)
+                        _collect=collect, passes=passes)
 
 
 def intersect_mesh2d_batch(
@@ -1643,12 +1692,12 @@ def _pack_count_rows(queries, rows: List[int], c_tier: int):
 
 
 def _collect_count(handles, queries, k_sel: int, extra_stats: Dict,
-                   row_of=None):
+                   passes: List[PassRecord], t_issue: float, row_of=None):
     """Shared collect for the count paths: one transfer, no re-run loop.
 
     ``row_of`` maps query index -> (handle key, local row) for the 2-D
     host-driven layout; None means a single handle covering all rows."""
-    fetched = jax.device_get(handles)
+    fetched = _fetch_pass(passes, handles, len(queries), k_sel, t_issue)
     results: List[Tuple[np.ndarray, Dict]] = []
     for qi, (probe, cands) in enumerate(queries):
         if row_of is None:
@@ -1694,13 +1743,17 @@ def dispatch_count_batch(
     b_tier = 1 << (len(queries) - 1).bit_length()
     rows = list(range(len(queries))) + [0] * (b_tier - len(queries))
     probe_vals, cand_vals, n_cands = _pack_count_rows(queries, rows, c_tier)
+    passes: List[PassRecord] = []
+    t_issue = time.perf_counter()
     EXEC_COUNTERS["count_calls"] += 1
     handles = _intersect_count_batch(
         probe_vals, cand_vals, n_cands, ts, gmaxes, k_sel, use_pallas)
     extra = {"c_tier": c_tier, "group_tuples": 1 << max(ts)}
     return PendingBatch(
         n_queries=len(queries), handles=handles,
-        _collect=lambda: _collect_count(handles, queries, k_sel, extra),
+        _collect=lambda: _collect_count(handles, queries, k_sel, extra,
+                                        passes, t_issue),
+        passes=passes,
     )
 
 
@@ -1745,6 +1798,8 @@ def dispatch_count_sharded_batch(
     b_tier = 1 << (len(queries) - 1).bit_length()
     rows = list(range(len(queries))) + [0] * (b_tier - len(queries))
     probe_vals, cand_vals, n_cands = _pack_count_rows(queries, rows, c_tier)
+    passes: List[PassRecord] = []
+    t_issue = time.perf_counter()
     EXEC_COUNTERS["count_calls"] += 1
     handles = _intersect_count_sharded_batch(
         probe_vals, cand_vals, n_cands, mesh, axis, ts, gmaxes, k_sel,
@@ -1753,7 +1808,9 @@ def dispatch_count_sharded_batch(
              "n_shards": n_shards}
     return PendingBatch(
         n_queries=len(queries), handles=handles,
-        _collect=lambda: _collect_count(handles, queries, k_sel, extra),
+        _collect=lambda: _collect_count(handles, queries, k_sel, extra,
+                                        passes, t_issue),
+        passes=passes,
     )
 
 
@@ -1804,6 +1861,8 @@ def dispatch_count_mesh2d_batch(
     slice_len = b_tier // n_replicas
     c_tier = 1 << (max(len(c) for _, c in queries) - 1).bit_length()
     k_sel = min(int(k), c_tier)
+    passes: List[PassRecord] = []
+    t_issue = time.perf_counter()
     handles = {}
     for rr in range(n_replicas):
         if rr * slice_len >= len(queries):
@@ -1835,7 +1894,8 @@ def dispatch_count_mesh2d_batch(
     return PendingBatch(
         n_queries=len(queries), handles=handles,
         _collect=lambda: _collect_count(handles, queries, k_sel, extra,
-                                        row_of=row_of),
+                                        passes, t_issue, row_of=row_of),
+        passes=passes,
     )
 
 
@@ -2093,13 +2153,17 @@ def dispatch_expr_batch(
 
     first_active = list(range(len(ordered)))
     first_cap = min(capacity or default_expr_capacity(ts, gmaxes), total)
+    passes: List[PassRecord] = []
+    first_issue = time.perf_counter()
     first_handles = issue(first_active, first_cap)
 
     def collect() -> List[Tuple[np.ndarray, Dict]]:
         results: List[Optional[Tuple[np.ndarray, Dict]]] = [None] * len(ordered)
         active, cap, handles = first_active, first_cap, first_handles
+        t_issue = first_issue
         while True:
-            root_h, r_h, maxc_h, over_h, subs_h = jax.device_get(handles)
+            root_h, r_h, maxc_h, over_h, subs_h = _fetch_pass(
+                passes, handles, len(active), cap, t_issue)
             rerun = []
             for row, qi in enumerate(active):
                 if over_h[row]:
@@ -2123,10 +2187,11 @@ def dispatch_expr_batch(
             active = rerun
             cap = total  # rare path: ONE re-run where no node can overflow
             EXEC_COUNTERS["expr_rerun_calls"] += 1
+            t_issue = time.perf_counter()
             handles = issue(active, cap)
 
     return PendingBatch(n_queries=len(ordered), handles=first_handles,
-                        _collect=collect)
+                        _collect=collect, passes=passes)
 
 
 def dispatch_expr_sharded_batch(
@@ -2170,13 +2235,17 @@ def dispatch_expr_sharded_batch(
         or default_expr_capacity_per_shard(ts, gmaxes, n_shards),
         local_total,
     )
+    passes: List[PassRecord] = []
+    first_issue = time.perf_counter()
     first_handles = issue(first_active, first_cap)
 
     def collect() -> List[Tuple[np.ndarray, Dict]]:
         results: List[Optional[Tuple[np.ndarray, Dict]]] = [None] * len(ordered)
         active, cap, handles = first_active, first_cap, first_handles
+        t_issue = first_issue
         while True:
-            root_h, r_h, maxc_h, over_h, subs_h = jax.device_get(handles)
+            root_h, r_h, maxc_h, over_h, subs_h = _fetch_pass(
+                passes, handles, len(active), cap, t_issue)
             rerun = []
             for row, qi in enumerate(active):
                 if over_h[:, row].any():
@@ -2202,10 +2271,11 @@ def dispatch_expr_sharded_batch(
             active = rerun
             cap = local_total  # one re-run at local total: no overflow
             EXEC_COUNTERS["expr_rerun_calls"] += 1
+            t_issue = time.perf_counter()
             handles = issue(active, cap)
 
     return PendingBatch(n_queries=len(ordered), handles=first_handles,
-                        _collect=collect)
+                        _collect=collect, passes=passes)
 
 
 def dispatch_expr_mesh2d_batch(
@@ -2266,14 +2336,18 @@ def dispatch_expr_mesh2d_batch(
         or default_expr_capacity_per_shard(ts, gmaxes, n_shards),
         local_total,
     )
+    passes: List[PassRecord] = []
+    first_issue = time.perf_counter()
     first_handles, first_slice_len = issue(first_active, first_cap)
 
     def collect() -> List[Tuple[np.ndarray, Dict]]:
         results: List[Optional[Tuple[np.ndarray, Dict]]] = [None] * len(ordered)
         active, cap = first_active, first_cap
+        t_issue = first_issue
         handles, slice_len = first_handles, first_slice_len
         while True:
-            fetched = jax.device_get(handles)
+            fetched = _fetch_pass(passes, handles, len(active), cap,
+                                  t_issue)
             rerun = []
             for rr, (root_h, r_h, maxc_h, over_h, subs_h) in fetched.items():
                 chunk_start = rr * slice_len
@@ -2307,10 +2381,11 @@ def dispatch_expr_mesh2d_batch(
             active = rerun
             cap = local_total  # one re-run at local total: no overflow
             EXEC_COUNTERS["expr_rerun_calls"] += 1
+            t_issue = time.perf_counter()
             handles, slice_len = issue(active, cap)
 
     return PendingBatch(n_queries=len(ordered), handles=first_handles,
-                        _collect=collect)
+                        _collect=collect, passes=passes)
 
 
 def intersect_expr_batch(queries, eshape, capacity=None, sub_keys=None):

@@ -149,6 +149,10 @@ class InFlightBucket:
         self.weight = weight
         self.obs = obs
         self.span = None
+        # set by an owner that scatters the results itself (see end_spans)
+        self.hold_spans = False
+        self._collect_span = None
+        self._scatter_span = None
         self._out: Optional[Dict[int, Tuple[np.ndarray, Dict]]] = None
         self._finished = False
         if obs is not None:
@@ -195,6 +199,41 @@ class InFlightBucket:
                 if self.span is not None:
                     self.span.end(error=True)
 
+    def _trace_collect(self, c0: float) -> None:
+        """Open the ``collect`` span at ``c0`` with a ``fetch`` child per
+        blocking transfer and a ``rerun`` child per overflow re-run (its
+        issue to the end of its fetch), read from the pass record; then
+        open ``scatter`` where the last transfer ended."""
+        tracer = self.obs.tracer
+        passes = self.pending.passes
+        span = tracer.start("collect", parent=self.span, start_us=c0 * 1e6)
+        rerun_rows = 0
+        for p in passes:
+            if p.pass_no:
+                rerun_rows += p.rows
+                tracer.span_at("rerun", p.t_issue * 1e6, p.t_fetched * 1e6,
+                               parent=span, rows=p.rows,
+                               capacity=p.capacity)
+            tracer.span_at("fetch", p.t_fetch * 1e6, p.t_fetched * 1e6,
+                           parent=span, **{"pass": p.pass_no})
+        self.span.set(passes=max(1, len(passes)), rerun_rows=rerun_rows)
+        self._collect_span = span
+        self._scatter_span = tracer.start(
+            "scatter", parent=span, rows=len(self.items),
+            start_us=passes[-1].t_fetched * 1e6 if passes else None)
+
+    def end_spans(self) -> None:
+        """Close the ``scatter``, ``collect`` and ``bucket`` spans.  With
+        ``hold_spans`` set, :meth:`collect` leaves them open for its
+        owner, whose result scatter (cache store, ticket resolution) they
+        then cover until it calls this; idempotent, and a no-op with
+        tracing off."""
+        if self._collect_span is not None:
+            self._scatter_span.end()
+            self._collect_span.end()
+            self._collect_span = None
+            self.span.end()
+
     def collect(self) -> Dict[int, Tuple[np.ndarray, Dict]]:
         """Block for the bucket's results; returns {query_index: (values,
         stats)} exactly as :func:`execute_bucket` does.
@@ -209,8 +248,11 @@ class InFlightBucket:
 
         With ``obs`` attached: observes the dispatch→collect latency,
         batch-size, and per-row survivor histograms, feeds the per-
-        signature :class:`~repro.obs.profile.ProfileStore`, and closes
-        the bucket span (retroactive ``device`` + ``collect`` children).
+        signature :class:`~repro.obs.profile.ProfileStore`, and (tracing
+        on) records the ``collect`` span with its ``fetch``, ``rerun`` and
+        ``scatter`` children, and ``passes`` / ``rerun_rows`` on the bucket
+        span.  The spans close on return, or, with ``hold_spans`` set, at
+        the owner's :meth:`end_spans`.
         """
         if self._out is not None:
             return self._out
@@ -223,6 +265,8 @@ class InFlightBucket:
         else:
             self._finish()
         c1 = time.perf_counter()
+        if self.span is not None:
+            self._trace_collect(c0)
         EXEC_COUNTERS["collect_us"] += int((c1 - c0) * 1e6)
         us = (c1 - self.dispatched_at) * 1e6
         out: Dict[int, Tuple[np.ndarray, Dict]] = {}
@@ -241,16 +285,9 @@ class InFlightBucket:
                 if "r" in stats:
                     self.obs.survivors.observe(stats["r"])
             self.obs.profile.observe(self.sig, len(self.items), us)
-            if self.span is not None:
-                # device stage = dispatch issued -> collect entered (the
-                # window jax's async dispatch computes under)
-                self.obs.tracer.span_at(
-                    "device", self.dispatch_end_at * 1e6, c0 * 1e6,
-                    parent=self.span)
-                self.obs.tracer.span_at(
-                    "collect", c0 * 1e6, c1 * 1e6, parent=self.span)
-                self.span.end()
         self._out = out
+        if not self.hold_spans:
+            self.end_spans()
         return out
 
 
@@ -294,8 +331,9 @@ def dispatch_bucket(
     ``obs``: an optional :class:`repro.obs.Obs`.  When given, the bucket
     reports through it — in-flight gauge + high-water, dispatch→collect
     latency / batch-size / survivor histograms, the per-signature profile
-    store, and (tracer enabled) a ``bucket`` span with retroactive
-    ``dispatch`` / ``device`` / ``collect`` children.  ``None`` keeps the
+    store, and (tracer enabled) a ``bucket`` span with a retroactive
+    ``dispatch`` child and, at collect, a ``collect`` child holding
+    ``fetch`` / ``rerun`` / ``scatter``.  ``None`` keeps the
     executor layer decoupled: only ``EXEC_COUNTERS`` is touched.
     """
     try:

@@ -84,6 +84,8 @@ def bitmap_filter_pallas(images: jnp.ndarray, *, interpret: bool) -> jnp.ndarray
         out_specs=pl.BlockSpec((1, SUBLANES, LANES), lambda bi, i: (bi, 0, i)),
         out_shape=jax.ShapeDtypeStruct((b, SUBLANES, gp), jnp.int32),
         interpret=interpret,
+        # the kernel's name in the compiled program and the device trace
+        name="bitmap_filter",
     )(packed)
     mask = out[:, 0, :g].astype(bool)
     return mask if batched else mask[0]

@@ -101,5 +101,7 @@ def pair_count_pallas(a_vals: jnp.ndarray, b_vals: jnp.ndarray, *,
         out_specs=pl.BlockSpec((SUBLANES, LANES), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((sp, LANES), jnp.int32),
         interpret=interpret,
+        # the kernel's name in the compiled program and the device trace
+        name="pair_count",
     )(a, b)
     return out[:s, 0]

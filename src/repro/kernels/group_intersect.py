@@ -78,5 +78,7 @@ def group_match_pallas(a_vals: jnp.ndarray, b_vals: jnp.ndarray, *,
         out_specs=pl.BlockSpec((SUBLANES, gap), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((sp, gap), jnp.int32),
         interpret=interpret,
+        # the kernel's name in the compiled program and the device trace
+        name="group_match",
     )(a, b)
     return out[:s, :ga].astype(bool)

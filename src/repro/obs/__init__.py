@@ -30,7 +30,15 @@ name                        type       what
                                        raised (balancer weight released)
 ``inflight_buckets``        Gauge      dispatched, not yet collected
 ``inflight_high_water``     Gauge      max of the above since reset
+``programs_compiled``       Counter    executables JAX built or loaded
+                                       from the persistent cache
+``compile_cache_loads``     Counter    of those, loaded from the cache
 ==========================  =========  =================================
+
+The two compile counters are fed by one ``jax.monitoring`` listener per
+process (:func:`_install_compile_listener`), which reports into every
+live ``Obs``; those with tracing on also get a ``compile`` root span over
+each compile's ``[end - secs, end]``.
 
 Engines default to the process-global instance (:func:`get_obs`) so
 ``EXEC_COUNTERS``-era code and tests keep one shared telemetry world;
@@ -39,6 +47,8 @@ pass ``obs=Obs(...)`` to any engine for an isolated one.
 from __future__ import annotations
 
 import threading
+import time
+import weakref
 from typing import Dict, Optional
 
 from repro.core.engine import EXEC_COUNTERS
@@ -66,6 +76,50 @@ def _exec_collector() -> Dict[str, float]:
     snapshot, re-keyed under ``exec_`` for the typed exposition."""
     return {f"exec_{k}": float(v)
             for k, v in EXEC_COUNTERS.snapshot().items()}
+
+
+# every live Obs, for the process-wide compile listener (held weakly, so
+# an engine's telemetry dies with it)
+_live_obs: "weakref.WeakSet[Obs]" = weakref.WeakSet()
+_listener_lock = threading.Lock()
+_listener_installed = False
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+
+
+def _on_compile(event: str, secs: float, **kw) -> None:
+    """A backend compile finished (JAX reports a load from the persistent
+    cache under the same event): count it in every live ``Obs`` and span
+    it in those that trace."""
+    if event != _COMPILE_EVENT:
+        return
+    end = time.perf_counter()
+    for obs in list(_live_obs):
+        obs.programs_compiled.inc()
+        tracer = obs.tracer
+        if tracer.enabled:
+            tracer.span_at("compile", (end - secs) * 1e6, end * 1e6,
+                           secs=round(secs, 6), fun=kw.get("fun_name"))
+
+
+def _on_event(event: str, **kw) -> None:
+    if event == _CACHE_HIT_EVENT:
+        for obs in list(_live_obs):
+            obs.compile_cache_loads.inc()
+
+
+def _install_compile_listener() -> None:
+    """Register the compile listeners with ``jax.monitoring``, once per
+    process."""
+    global _listener_installed
+    with _listener_lock:
+        if _listener_installed:
+            return
+        import jax.monitoring
+
+        jax.monitoring.register_event_duration_secs_listener(_on_compile)
+        jax.monitoring.register_event_listener(_on_event)
+        _listener_installed = True
 
 
 class Obs:
@@ -98,6 +152,14 @@ class Obs:
         self.inflight_high_water = r.gauge(
             "inflight_high_water", "max concurrent in-flight since reset",
             track_max=True)
+        self.programs_compiled = r.counter(
+            "programs_compiled",
+            "executables JAX built or loaded from the persistent cache")
+        self.compile_cache_loads = r.counter(
+            "compile_cache_loads",
+            "executables loaded from the persistent compile cache")
+        _live_obs.add(self)
+        _install_compile_listener()
 
     def snapshot(self) -> Dict:
         return self.registry.snapshot()

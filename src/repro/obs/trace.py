@@ -15,8 +15,9 @@ client):
   than picking one parent).
 - A **span** is a named interval with attrs.  Spans form trees via
   ``parent_id``.  The taxonomy used by the serving stack is documented in
-  ``docs/OBSERVABILITY.md``: request → {plan, admission}; bucket →
-  {dispatch, device, collect}.
+  ``docs/OBSERVABILITY.md``: request → {late, plan, admission}; bucket →
+  {dispatch, collect → {fetch, rerun, scatter}}; and the flusher's own
+  roots ``wait``, ``take``, ``snapshot``, ``replan``, plus ``compile``.
 - Clock is ``time.perf_counter`` scaled to µs (injectable for tests).
 
 Lock-cheapness: the disabled tracer (the default) returns one shared
@@ -67,12 +68,14 @@ class Span:
     def child(self, name: str, **attrs) -> "Span":
         return self.tracer.start(name, parent=self, **attrs)
 
-    def end(self, **attrs) -> None:
+    def end(self, end_us: Optional[float] = None, **attrs) -> None:
+        """Close the span now, or at ``end_us`` on the tracer's clock
+        when the instant was read already (e.g. a ticket's resolution)."""
         if self.end_us is not None:
             return
         if attrs:
             self.attrs.update(attrs)
-        self.tracer._finish(self)
+        self.tracer._finish(self, end_us)
 
     @property
     def duration_us(self) -> float:
@@ -117,7 +120,7 @@ class NullSpan:
     def child(self, name: str, **attrs) -> "NullSpan":
         return self
 
-    def end(self, **attrs) -> None:
+    def end(self, end_us: Optional[float] = None, **attrs) -> None:
         return None
 
     def __enter__(self) -> "NullSpan":
@@ -152,14 +155,14 @@ class Tracer:
                  clock=time.perf_counter):
         self.enabled = enabled
         self.max_finished = max(1, int(max_finished))
-        self._clock = clock
+        self.clock = clock
         self._lock = threading.Lock()
         self._open: Dict[int, Span] = {}
         self._finished: List[Span] = []
         self._dropped = 0
 
     def _now_us(self) -> float:
-        return self._clock() * 1e6
+        return self.clock() * 1e6
 
     def new_trace_id(self) -> int:
         return next(_ids)
@@ -190,9 +193,10 @@ class Tracer:
     def span_at(self, name: str, start_us: float, end_us: float,
                 parent: Optional[Span] = None, **attrs):
         """Record an already-elapsed interval as a closed span.  Used for
-        stages whose boundaries are only known after the fact — e.g. the
-        "device" span is the dispatch-end → collect-start window, bounded
-        once collect returns."""
+        stages whose boundaries are only known after the fact — e.g. a
+        bucket's ``fetch`` and ``rerun`` spans, read from its pass record
+        once collect returns, or a ``compile`` reported with its
+        duration."""
         if not self.enabled:
             return NULL_SPAN
         if parent is not None and parent.enabled:
@@ -214,8 +218,8 @@ class Tracer:
             del self._finished[:drop]
             self._dropped += drop
 
-    def _finish(self, span: Span) -> None:
-        span.end_us = self._now_us()
+    def _finish(self, span: Span, end_us: Optional[float] = None) -> None:
+        span.end_us = self._now_us() if end_us is None else end_us
         with self._lock:
             self._open.pop(span.span_id, None)
             self._store(span)

@@ -87,19 +87,27 @@ class Ticket:
     raises instead of clobbering a result some caller may already have
     read (the failed-then-retried-bucket hazard).
 
+    ``resolved_at`` is stamped once, on ``clock`` (the engine's), inside
+    the single-shot resolution, before ``done`` is set: when the answer
+    became visible.  None until then.
+
     Tracing: the submitting engine may stamp ``span`` (the request's root
     span) and ``admission_span`` (the queue-wait child) plus ``obs``.
     The root span is closed inside :meth:`_record_wait` — i.e. exactly
     once, under the same single-shot guarantee as resolution itself, on
-    every path (value, error, cache hit, host plan) — which is the
-    "every submitted ticket yields exactly one closed root span"
-    invariant the observability tests gate.
+    every path (value, error, cache hit, host plan), at ``resolved_at``
+    when the tracer shares the ticket's clock — which is the "every
+    submitted ticket yields exactly one closed root span" invariant the
+    observability tests gate.
     """
 
     submitted_at: float
     deadline_us: float
     wait_us: float = 0.0
     error: Optional[BaseException] = None
+    resolved_at: Optional[float] = None
+    clock: Callable[[], float] = dataclasses.field(
+        default=time.perf_counter, repr=False, compare=False)
     span: Any = dataclasses.field(default=None, repr=False, compare=False)
     admission_span: Any = dataclasses.field(
         default=None, repr=False, compare=False)
@@ -153,7 +161,11 @@ class Ticket:
         if self.obs is not None:
             self.obs.queue_wait.observe(wait_us)
         if self.span is not None:
-            self.span.end(wait_us=round(wait_us, 1),
+            tracer = getattr(self.span, "tracer", None)
+            at_us = (self.resolved_at * 1e6
+                     if tracer is not None and tracer.clock is self.clock
+                     else None)
+            self.span.end(end_us=at_us, wait_us=round(wait_us, 1),
                           deadline_violation=violated,
                           error=(type(self.error).__name__
                                  if self.error is not None else None))
@@ -163,6 +175,7 @@ class Ticket:
             raise RuntimeError("ticket already resolved — single-shot")
         self._value = value
         self.wait_us = wait_us
+        self.resolved_at = self.clock()
         self._record_wait(wait_us)
         self._done.set()  # publish AFTER the payload writes
 
@@ -171,6 +184,7 @@ class Ticket:
             raise RuntimeError("ticket already resolved — single-shot")
         self.error = exc
         self.wait_us = wait_us
+        self.resolved_at = self.clock()
         self._record_wait(wait_us)
         self._done.set()  # publish AFTER the payload writes
 
@@ -224,6 +238,7 @@ class AdmissionQueue:
             submitted_at=(self.clock() if submitted_at is None
                           else float(submitted_at)),
             deadline_us=self.deadline_us if deadline_us is None else float(deadline_us),
+            clock=self.clock,
         )
         if span is not None:
             ticket.span = span

@@ -704,14 +704,26 @@ class AsyncSearchEngine(SearchEngine):
         ``_flusher_idle_s`` for its results); with an empty window it
         sleeps exactly until the next admission deadline (or the idle
         re-check when the queue is empty), cut short by ``submit``'s wake
-        event."""
+        event.
+
+        Tracing on, every stretch of the loop lies under a span: ``wait``
+        (asleep with an empty window), ``take``, ``snapshot``, and the
+        ``replan`` / ``bucket`` / ``collect`` spans of the buckets it
+        dispatches and collects."""
+        tracer = self.obs.tracer
         while True:
             next_us = self.admission.next_deadline_in_us()
             if self._inflight_count() == 0:
                 timeout = (self._flusher_idle_s if next_us is None
                            else max(0.0, next_us * 1e-6))
                 if timeout > 0:
-                    self._wake.wait(timeout)
+                    if tracer.enabled:
+                        with tracer.start(
+                                "wait", queued=self.admission.pending(),
+                                timeout_ms=round(timeout * 1e3, 3)):
+                            self._wake.wait(timeout)
+                    else:
+                        self._wake.wait(timeout)
             if self._stop_flusher.is_set():
                 # collect whatever is still in flight before exiting so
                 # stop()'s drain only deals with the queue, not the window
@@ -724,9 +736,12 @@ class AsyncSearchEngine(SearchEngine):
                 now_mono = time.monotonic()
                 if now_mono - self._last_snapshot_at >= self.snapshot_every_s:
                     self._last_snapshot_at = now_mono
+                    span = tracer.start("snapshot") if tracer.enabled else None
                     self.obs.ring.push(now_mono, self.obs.registry.snapshot())
+                    if span is not None:
+                        span.end()
             try:
-                self._flush(self.admission.take_due())
+                self._flush(self._take_due())
                 # reap everything already finished on the device...
                 while self._collect_one(ready_only=True):
                     pass
@@ -769,9 +784,11 @@ class AsyncSearchEngine(SearchEngine):
         one ``request`` root span with a ``plan`` child; the root carries
         the resolved ``route`` (``cache`` / ``subcache`` / ``host`` /
         ``device`` + sig label) and is closed exactly once at ticket
-        resolution, whichever path resolves it.
+        resolution, whichever path resolves it.  With ``arrival_at`` in the
+        past, the root starts at the arrival and a ``late`` child covers
+        arrival -> entry to ``submit``.
         """
-        span = (self.obs.tracer.start("request")
+        span = (self._request_span(arrival_at)
                 if self.obs.tracer.enabled else None)
         try:
             if span is not None:
@@ -823,6 +840,21 @@ class AsyncSearchEngine(SearchEngine):
             self._collect_all()
         return ticket
 
+    def _request_span(self, arrival_at: Optional[float]):
+        """Open a request's root span (tracing on), backdated to a past
+        ``arrival_at`` with a ``late`` child up to now when the engine's
+        clock is the tracer's."""
+        tracer = self.obs.tracer
+        if arrival_at is None or tracer.clock is not self.clock:
+            return tracer.start("request")
+        now_us = tracer.clock() * 1e6
+        at_us = float(arrival_at) * 1e6
+        if at_us >= now_us:
+            return tracer.start("request")
+        span = tracer.start("request", start_us=at_us)
+        tracer.span_at("late", at_us, now_us, parent=span)
+        return span
+
     def pump(self) -> int:
         """Flush buckets whose deadline budget has expired (and any that
         filled their tier since the last call).  Returns #buckets flushed.
@@ -831,7 +863,7 @@ class AsyncSearchEngine(SearchEngine):
         synchronous, overlapped inside.  Manual loops call it on a timer —
         the deadline guarantee is only as fine-grained as the pump
         cadence."""
-        count = self._flush(self.admission.take_due())
+        count = self._flush(self._take_due())
         self._collect_all()
         return count
 
@@ -867,11 +899,23 @@ class AsyncSearchEngine(SearchEngine):
         """
         now = self.clock()
         arrival = now if arrival_at is None else min(float(arrival_at), now)
-        ticket = Ticket(submitted_at=arrival, deadline_us=0.0)
+        ticket = Ticket(submitted_at=arrival, deadline_us=0.0,
+                        clock=self.clock)
         ticket.span = span
         ticket.obs = self.obs
         ticket.resolve(result, wait_us=(now - arrival) * 1e6)
         return ticket
+
+    def _take_due(self):
+        """``admission.take_due()``, under a ``take`` span when tracing."""
+        tracer = self.obs.tracer
+        if not tracer.enabled:
+            return self.admission.take_due()
+        span = tracer.start("take")
+        buckets = self.admission.take_due()
+        span.end(buckets=len(buckets),
+                 queries=sum(len(entries) for _, entries in buckets))
+        return buckets
 
     def _flush(self, buckets) -> int:
         """Dispatch flushed buckets into the in-flight window; returns
@@ -901,7 +945,7 @@ class AsyncSearchEngine(SearchEngine):
                     self._dispatch_one(sig, entries)
                     count += 1
                     if not pending:
-                        pending.extend(self.admission.take_due())
+                        pending.extend(self._take_due())
             if pending and not self._collect_one():
                 # window full but no flight to pop: other threads are
                 # mid-collect — wait for one to finish and free a slot
@@ -924,6 +968,8 @@ class AsyncSearchEngine(SearchEngine):
         ``deadline_us`` bounds.
         """
         flush_at = self.clock()
+        span = (self.obs.tracer.start("replan", queries=len(entries))
+                if self.obs.tracer.enabled else None)
         for ticket, _ in entries:
             # queue wait is over the moment the flush picks the bucket up
             if ticket.admission_span is not None:
@@ -943,6 +989,8 @@ class AsyncSearchEngine(SearchEngine):
                 ticket.resolve_error(exc, wait_us=wait_us)
             else:
                 ticket.resolve(result, wait_us=wait_us)
+        if span is not None:
+            span.end(stale=len(entries) - len(live))
         if not live:
             return
         items = [(row, plan) for row, (_, plan) in enumerate(live)]
@@ -1038,6 +1086,7 @@ class AsyncSearchEngine(SearchEngine):
         """Collect one flight's results and resolve its tickets (cache
         store under the dispatch-time generation, error fan-out on a
         failed collect)."""
+        flight.bucket.hold_spans = True
         try:
             by_row = flight.bucket.collect()
         except Exception as exc:
@@ -1046,13 +1095,18 @@ class AsyncSearchEngine(SearchEngine):
                     exc,
                     wait_us=(flight.flush_at - ticket.submitted_at) * 1e6)
             return
-        for row, (ticket, plan) in enumerate(flight.entries):
-            res, stats = by_row[row]
-            result = QueryResult(res, stats.get("batch_us", 0.0),
-                                 _device_result_name(stats), stats)
-            self._store(plan, result, generation=flight.generation)
-            wait_us = (flight.flush_at - ticket.submitted_at) * 1e6
-            ticket.resolve(result, wait_us=wait_us)
+        try:
+            for row, (ticket, plan) in enumerate(flight.entries):
+                res, stats = by_row[row]
+                result = QueryResult(res, stats.get("batch_us", 0.0),
+                                     _device_result_name(stats), stats)
+                self._store(plan, result, generation=flight.generation)
+                wait_us = (flight.flush_at - ticket.submitted_at) * 1e6
+                ticket.resolve(result, wait_us=wait_us)
+        finally:
+            # the bucket's scatter span covers the cache stores and the
+            # ticket resolutions above
+            flight.bucket.end_spans()
 
 
 @dataclasses.dataclass

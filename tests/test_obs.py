@@ -346,8 +346,8 @@ def test_error_path_closes_root_span(postings, monkeypatch):
 def test_bucket_spans_overlap_in_window(postings):
     """With the overlapped window the drain dispatches buckets
     back-to-back before collecting: their spans must genuinely overlap,
-    and each carries dispatch/device/collect children plus the member
-    request trace ids."""
+    and each carries dispatch/collect children plus the member request
+    trace ids."""
     obs = Obs(trace=True)
     eng = AsyncSearchEngine(postings, seed=3, flush_tier=64,
                             result_cache=0, max_inflight=8, obs=obs)
@@ -365,11 +365,12 @@ def test_bucket_spans_overlap_in_window(postings):
     for s in bspans:
         assert s.attrs["traces"], "bucket span lost its member traces"
         assert s.attrs["batch"] >= 1
-    for name in ("dispatch", "device", "collect"):
+    for name in ("dispatch", "collect"):
         stage = obs.tracer.finished(name)
         assert len(stage) == n_buckets
         by_parent = {s.parent_id for s in stage}
         assert by_parent == {s.span_id for s in bspans}
+    assert obs.tracer.finished("device") == []
     assert obs.tracer.open_count() == 0
     # profile store attributed every executed signature
     assert len(obs.profile.signatures()) >= 1
@@ -432,6 +433,165 @@ def test_flusher_fills_snapshot_ring(postings):
             time.sleep(0.01)
     assert len(obs.ring) >= 1
     assert resolved_in_latest() >= len(log)
+
+
+# ---------------------------------------------------------------------------
+# the served path under spans: flusher loop, collect's passes, compiles
+# ---------------------------------------------------------------------------
+
+def _serve(eng, log, gap_s=0.002):
+    """Serve ``log`` through the background flusher, one query every
+    ``gap_s`` with ``arrival_at`` stamped, and wait for every answer."""
+    with eng:
+        t0 = time.perf_counter()
+        tickets = []
+        for i, q in enumerate(log):
+            tickets.append(eng.submit(q, arrival_at=t0 + i * gap_s))
+            time.sleep(gap_s)
+        for t in tickets:
+            assert t.wait(timeout=60.0)
+    return tickets
+
+
+def _children(spans, parent):
+    return [s for s in spans if s.parent_id == parent.span_id]
+
+
+def test_served_buckets_have_fetch_and_scatter_and_no_device(postings):
+    obs = Obs(trace=True, max_finished_spans=100_000)
+    eng = AsyncSearchEngine(postings, seed=3, flush_tier=4,
+                            deadline_us=1000.0, result_cache=0,
+                            max_inflight=8, obs=obs)
+    log = zipf_query_log(sorted(eng.index), 24, seed=11)
+    _serve(eng, log)
+    spans = obs.tracer.finished()
+    assert obs.tracer.open_count() == 0
+    assert not [s for s in spans if s.name == "device"]
+    buckets = [s for s in spans if s.name == "bucket"]
+    assert buckets
+    for b in buckets:
+        (collect,) = [s for s in _children(spans, b) if s.name == "collect"]
+        names = [s.name for s in _children(spans, collect)]
+        assert names.count("fetch") == b.attrs["passes"]
+        assert names.count("scatter") == 1
+        assert names.count("rerun") == b.attrs["passes"] - 1
+        assert collect.start_us >= b.start_us
+        assert collect.end_us <= b.end_us
+    # every request answered from a bucket ends at its ticket's resolution
+    assert {s.name for s in spans} >= {"wait", "take", "replan", "late"}
+    for late in (s for s in spans if s.name == "late"):
+        assert late.duration_us >= 0
+
+
+def test_wait_never_overlaps_the_flushers_work(postings):
+    obs = Obs(trace=True, max_finished_spans=100_000)
+    eng = AsyncSearchEngine(postings, seed=3, flush_tier=4,
+                            deadline_us=1000.0, result_cache=0,
+                            max_inflight=8, obs=obs)
+    _serve(eng, zipf_query_log(sorted(eng.index), 24, seed=12), gap_s=0.004)
+    spans = obs.tracer.finished()
+    waits = [s for s in spans if s.name == "wait"]
+    work = [s for s in spans if s.name in ("take", "dispatch", "collect")]
+    assert waits and work
+    for w in waits:
+        assert w.attrs["timeout_ms"] > 0 and w.attrs["queued"] >= 0
+        for s in work:
+            assert s.end_us <= w.start_us or s.start_us >= w.end_us, (w, s)
+
+
+def test_overflowing_bucket_records_one_rerun(postings, monkeypatch):
+    """A survivor capacity of 1 makes buckets overflow: each such bucket
+    records one ``rerun`` over exactly its overflowing queries, and
+    ``passes`` 2; the rerun spans match the executor's own count."""
+    monkeypatch.setattr("repro.exec.plan.default_capacity", lambda ts: 1)
+    obs = Obs(trace=True, max_finished_spans=100_000)
+    eng = AsyncSearchEngine(postings, seed=3, flush_tier=4,
+                            deadline_us=1000.0, result_cache=0,
+                            max_inflight=8, obs=obs)
+    before = EXEC_COUNTERS["rerun_calls"]
+    tickets = _serve(eng, zipf_query_log(sorted(eng.index), 24, seed=11))
+    spans = obs.tracer.finished()
+    reruns = [s for s in spans if s.name == "rerun"]
+    assert reruns
+    assert len(reruns) == EXEC_COUNTERS["rerun_calls"] - before
+    by_id = {s.span_id: s for s in spans}
+    overflowed = {}
+    for t in tickets:
+        stats = t.value.stats
+        if (t.span.attrs.get("route") == "device"
+                and stats["capacity"] == stats["group_tuples"]):
+            key = t.span.attrs["bucket_span"]
+            overflowed[key] = overflowed.get(key, 0) + 1
+    for r in reruns:
+        bucket = by_id[by_id[r.parent_id].parent_id]
+        assert bucket.attrs["passes"] == 2
+        assert r.attrs["rows"] == bucket.attrs["rerun_rows"]
+        assert r.attrs["rows"] == overflowed[bucket.span_id]
+        assert r.attrs["capacity"] > 1
+    for b in (s for s in spans if s.name == "bucket"):
+        assert b.attrs["passes"] == 1 + (b.span_id in overflowed)
+
+
+def test_fresh_shape_records_one_compile():
+    obs = Obs(trace=True)
+    quiet = Obs()
+    before = quiet.programs_compiled.value
+    x = np.arange(7 * 13, dtype=np.float32).reshape(7, 13)
+    jax.jit(lambda a: a * 3.0 + 1.0)(x).block_until_ready()
+    (span,) = obs.tracer.finished("compile")
+    assert span.parent_id is None and span.attrs["secs"] >= 0
+    assert span.duration_us == pytest.approx(span.attrs["secs"] * 1e6,
+                                             abs=1.0)
+    assert obs.programs_compiled.value == 1
+    assert quiet.programs_compiled.value == before + 1
+    assert quiet.tracer.finished() == []  # counted, never spanned
+    snap = obs.snapshot()["counters"]
+    assert snap["programs_compiled"] == 1
+    assert snap["compile_cache_loads"] == 0
+
+
+def test_disabled_tracer_records_nothing_while_serving(postings):
+    obs = Obs()
+    eng = AsyncSearchEngine(postings, seed=3, flush_tier=4,
+                            deadline_us=1000.0, result_cache=0,
+                            max_inflight=8, snapshot_every_s=0.001, obs=obs)
+    tickets = _serve(eng, zipf_query_log(sorted(eng.index), 12, seed=13))
+    assert all(t.done for t in tickets)
+    assert obs.tracer.finished() == [] and obs.tracer.open_count() == 0
+    assert obs.batch_size.count >= 1
+
+
+@pytest.mark.parametrize("route", ["device", "cache", "host", "error"])
+def test_resolved_at_follows_submitted_at(postings, monkeypatch, route):
+    """``Ticket.resolved_at`` is stamped once, on the engine clock, at or
+    after ``submitted_at``, and the request's root span ends there."""
+    obs = Obs(trace=True)
+    eng = AsyncSearchEngine(postings, seed=3, flush_tier=64,
+                            max_inflight=8, obs=obs)
+    q = zipf_query_log(sorted(eng.index), 1, seed=11)[0]
+    if route == "cache":
+        eng.submit(q)
+        eng.drain()
+    elif route == "host":
+        q = [-1]  # an unknown term: answered empty on the host
+    elif route == "error":
+        monkeypatch.setattr(
+            "repro.serve.search.dispatch_bucket",
+            lambda *a, **kw: (_ for _ in ()).throw(RuntimeError("boom")))
+    late = time.perf_counter() - 0.01
+    ticket = eng.submit(q, arrival_at=late)
+    eng.drain()
+    assert ticket.done and ticket.resolved_at is not None
+    assert ticket.submitted_at == pytest.approx(late)
+    assert ticket.resolved_at >= ticket.submitted_at
+    assert (ticket.error is not None) == (route == "error")
+    root = ticket.span
+    assert root.attrs["route"] == ("device" if route == "error" else route)
+    assert root.end_us == pytest.approx(ticket.resolved_at * 1e6)
+    assert root.start_us == pytest.approx(late * 1e6)
+    (late_span,) = [s for s in obs.tracer.finished("late")
+                    if s.parent_id == root.span_id]
+    assert late_span.end_us <= root.end_us
 
 
 # ---------------------------------------------------------------------------
