@@ -54,8 +54,8 @@ def pair_count(a_vals: jnp.ndarray, b_vals: jnp.ndarray,
                use_pallas="auto") -> jnp.ndarray:
     """(S, ga), (S, gb) sentinel-padded -> (S,) int32 match counts.
 
-    The count-only twin of :func:`group_match` — same broadcast-equality
-    tile, reduced to one scalar per row, so the suggestion path never
+    The count-only twin of :func:`group_match` — the same broadcast-equality
+    test, reduced to one scalar per row, so the suggestion path never
     materializes survivor buffers.  Leading batch axes supported:
     (..., S, ga) x (..., S, gb) -> (..., S).
     """

@@ -39,14 +39,22 @@ def test_bitmap_filter_all_pass_all_fail():
     assert not np.asarray(bitmap_filter_pallas(zeros, interpret=True)).any()
 
 
-@pytest.mark.parametrize("S", [1, 8, 57, 256])
-@pytest.mark.parametrize("ga,gb", [(8, 8), (16, 32), (40, 16), (128, 128)])
+# S crosses the 128-row lane chunk and the 2,048-row grid step; the widths
+# cover the cell's (32, 32) and the 8-sublane padding of ga
+@pytest.mark.parametrize("S", [1, 8, 57, 256, 127, 129, 2 * 2048 + 3])
+@pytest.mark.parametrize("ga,gb", [(8, 8), (16, 32), (40, 16), (128, 128),
+                                   (32, 32), (32, 64), (8, 128)])
 def test_group_match_sweep(S, ga, gb):
     rng = np.random.default_rng(S * 100 + ga + gb)
     a = rng.integers(0, 500, size=(S, ga)).astype(np.int32)
     b = rng.integers(0, 500, size=(S, gb)).astype(np.int32)
     a[rng.random((S, ga)) < 0.25] = -1
     b[rng.random((S, gb)) < 0.25] = -1
+    # rows that are all padding, on either side; at S = 1 the one row stays
+    # real so that the case still compares matches
+    if S > 1:
+        a[0] = -1
+        b[S - 1] = -1
     out_ref = np.asarray(ref.group_match_ref(jnp.asarray(a), jnp.asarray(b)))
     out_pal = np.asarray(
         group_match_pallas(jnp.asarray(a), jnp.asarray(b), interpret=True))
@@ -71,13 +79,16 @@ def test_bitmap_filter_batched_folds_grid(B, G):
             out_ref[b], np.asarray(bitmap_filter_pallas(x[b], interpret=True)))
 
 
-@pytest.mark.parametrize("B,S", [(1, 8), (4, 13), (6, 64)])
+@pytest.mark.parametrize("B,S", [(1, 8), (4, 13), (6, 64), (3, 127),
+                                 (2, 2051)])
 def test_group_match_batched_folds_rows(B, S):
     rng = np.random.default_rng(B * 31 + S)
     a = rng.integers(0, 300, size=(B, S, 16)).astype(np.int32)
     b = rng.integers(0, 300, size=(B, S, 24)).astype(np.int32)
     a[rng.random(a.shape) < 0.25] = -1
     b[rng.random(b.shape) < 0.25] = -1
+    a[0, S - 1] = -1
+    b[B - 1, 0] = -1
     out_ref = np.asarray(ref.group_match_ref(jnp.asarray(a), jnp.asarray(b)))
     assert out_ref.shape == (B, S, 16)
     out_pal = np.asarray(

@@ -72,15 +72,24 @@ def _check_compiled(lowered, kernel=True):
     return text
 
 
-@pytest.mark.parametrize("kernel", ["bitmap_filter", "group_match",
-                                    "pair_count"])
-def test_kernel_compiles_for_v5e(chip, kernel):
+@pytest.mark.parametrize("kernel,widths", [
+    pytest.param("bitmap_filter", None, id="bitmap_filter"),
+    pytest.param("group_match", (4, 1 << 12, 64, 64), id="group_match"),
+    # the largest re-run the gov2-conj cell warms: B = 256 at capacity 2^11
+    pytest.param("group_match", (256, 1 << 11, 32, 32),
+                 id="group_match-rerun-32x32"),
+    pytest.param("group_match", (256, 1 << 11, 32, 64),
+                 id="group_match-rerun-32x64"),
+    pytest.param("pair_count", None, id="pair_count"),
+])
+def test_kernel_compiles_for_v5e(chip, kernel, widths):
     if kernel == "bitmap_filter":
         fn = lambda x: bitmap_filter_pallas(x, interpret=False)
         args = (_spec(chip, (4, 4, 1 << 12, 2, 8), jnp.uint32),)
     elif kernel == "group_match":
         fn = lambda a, b: group_match_pallas(a, b, interpret=False)
-        args = (_spec(chip, (4, 1 << 12, 64)),) * 2
+        bsz, s, ga, gb = widths
+        args = (_spec(chip, (bsz, s, ga)), _spec(chip, (bsz, s, gb)))
     else:
         fn = lambda a, b: pair_count_pallas(a, b, interpret=False)
         args = (_spec(chip, (1, 64, 1 << 10, 64)),) * 2
