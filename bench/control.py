@@ -3,9 +3,9 @@
     python3 bench/control.py --workload <cell> --seconds <s> --seeds 1,2,3
 
 For each seed the cell's data and log are generated exactly as a run
-makes them, the control (``reference.control``: the filter without the
-verification) answers every request due in the window in the program's
-place, and the comparison that decides ``correct``
+makes them, the control of the traffic's request kind (``control`` in
+``bench/kinds/<kind>.py``) answers every request due in the window in the
+program's place, and the comparison that decides ``correct``
 (``harness.compare_log``, as in a run) counts its differences from the
 reference.  Prints one JSON line per seed with the
 numbers compared and the verdict.  Needs no device: the control replaces
@@ -29,15 +29,15 @@ def main(argv=None) -> int:
     from bench import gen, harness, reference
 
     c = harness.cell(args.workload)
+    kind = harness.kind_of(c["traffic"])
     for seed in (int(s) for s in args.seeds.split(",")):
-        lists = gen.posting_lists(c["config"], seed)
-        pool = gen.query_pool(c["traffic"], gen.query_terms(c["traffic"],
-                                                            lists))
+        data = kind.data(c["config"], seed)
+        pool = kind.pool(c["traffic"], data)
         lg = gen.build_log(c["traffic"], pool, c["traffic"]["rate_qps"],
                            args.seconds, seed)
-        ctl = {lg.pool[i]: reference.control(lists, lg.pool[i])
+        ctl = {lg.pool[i]: kind.control(data, lg.pool[i])
                for i in set(lg.which.tolist())}
-        counts, _ = harness.compare_log(lists, lg,
+        counts, _ = harness.compare_log(kind, data, lg,
                                         [ctl[lg.pool[i]] for i in lg.which])
         print(json.dumps({"workload": args.workload, "seed": seed,
                           "requests": len(lg),

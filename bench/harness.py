@@ -7,8 +7,12 @@ from its own file, found by the name ``BENCHMARK.json`` gives:
 
 - ``bench/configs/<config>.json``: the deployment (sizes, posting band,
   engine settings, latency limit, knee);
-- ``bench/traffic/<traffic>.json``: the mix (k-term shares, the terms it
-  draws from, distinct conjunctions, rate);
+- ``bench/traffic/<traffic>.json``: the mix (its request kind, the shares
+  of its request shapes, the terms it draws from, distinct requests,
+  rate);
+- ``bench/kinds/<kind>.py``: what a request of the traffic's kind is, and
+  all that depends on it: data, engine, pool, warm-up, submission, answer,
+  reference and control (``bench/kinds/__init__.py``);
 - ``bench/readers/<metric>.py``: the reader of a per-layer metric, where
   ``<metric>`` is the metric's name up to its first dot, so a metric
   ``plan_us.lat`` and a later ``plan_us.over`` share ``plan_us.py``.
@@ -30,10 +34,11 @@ from typing import Callable, Dict, List, Optional
 
 import numpy as np
 
-from . import gen, reference, trace, window
+from . import gen, kinds, reference, trace, window
 
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
+KINDS = BENCH / "kinds"
 DRAIN_S = 60.0          # how long past the close a late answer is waited for
 # Batch tiers warmed for every signature.  The admission queue hands over
 # a bucket whole, however far past ``flush_tier`` it grew while the host
@@ -42,7 +47,7 @@ DRAIN_S = 60.0          # how long past the close a late answer is waited for
 # covers buckets of up to 256 queries of one signature: a stall of about
 # two seconds at the rates the cells offer.
 WARM_TIER_MAX = 256
-PRESERVE = 64           # pool queries served through the flusher in set-up
+PRESERVE = 64           # pool requests served through the flusher in set-up
 
 
 def log(msg: str) -> None:
@@ -84,13 +89,25 @@ def peaks(device_kind: str) -> Dict:
     return table[device_kind]
 
 
-def reader(metric: str) -> Callable:
-    base = metric.split(".")[0]
-    path = BENCH / "readers" / f"{base}.py"
-    spec = importlib.util.spec_from_file_location(f"bench_reader_{base}", path)
+def _load(path: Path, name: str):
+    spec = importlib.util.spec_from_file_location(name, path)
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
-    return module.read
+    return module
+
+
+def reader(metric: str) -> Callable:
+    base = metric.split(".")[0]
+    return _load(BENCH / "readers" / f"{base}.py", f"bench_reader_{base}").read
+
+
+def kind_of(traffic: Dict):
+    """The module of the traffic's request kind, ``<KINDS>/<kind>.py``."""
+    name = traffic.get("kind", kinds.DEFAULT)
+    path = KINDS / f"{name}.py"
+    if not path.is_file():
+        raise SystemExit(f"no request kind {name!r}: {path} is missing")
+    return _load(path, f"bench_kind_{name}")
 
 
 # ----------------------------------------------------------------------
@@ -144,9 +161,9 @@ class Served:
     """A built and warmed engine with what its cell serves."""
 
     engine: object
-    lists: Dict[int, np.ndarray]
-    terms: List[int]
-    pool: List[tuple]
+    kind: object          # the request kind's module
+    data: object
+    pool: List
     phases: Dict[str, float]
     counts: CompileCount
     obs: object
@@ -156,66 +173,39 @@ def build(config: Dict, traffic: Dict, seed: int, trace_spans: bool,
           counts: CompileCount) -> Served:
     """Generate the data, build the engine, warm every program the log
     can run.  Prints each phase."""
-    from repro.core.engine import pow2_tiers, warm_executables
+    from repro.core.engine import pow2_tiers
     from repro.obs import Obs
-    from repro.serve.search import AsyncSearchEngine
 
+    kind = kind_of(traffic)
     phases: Dict[str, float] = {}
     t = time.perf_counter()
-    lists = gen.posting_lists(config, seed)
-    terms = gen.query_terms(traffic, lists)
-    pool = gen.query_pool(traffic, terms)
+    data = kind.data(config, seed)
+    pool = kind.pool(traffic, data)
     phases["data_s"] = time.perf_counter() - t
-    n_post = sum(len(v) for v in lists.values())
-    log(f"data: {len(lists)} terms, {n_post} postings, {len(terms)} query "
-        f"terms, {len(pool)} distinct conjunctions ({phases['data_s']:.3f} s)")
+    log(f"data: {len(pool)} distinct requests of kind "
+        f"{traffic.get('kind', kinds.DEFAULT)} ({phases['data_s']:.3f} s)")
 
-    eng_cfg = config["engine"]
     obs = Obs(trace=trace_spans, max_finished_spans=4_000_000)
-    t = time.perf_counter()
-    engine = AsyncSearchEngine(
-        lists, w=eng_cfg["w"], m=eng_cfg["m"], use_device=True,
-        deadline_us=eng_cfg["deadline_us"], flush_tier=eng_cfg["flush_tier"],
-        max_inflight=eng_cfg["max_inflight"],
-        result_cache=eng_cfg["result_cache"],
-        hashbin_ratio=eng_cfg["hashbin_ratio"], obs=obs)
-    built = time.perf_counter() - t
-    phases["preprocess_s"] = engine.build_s
-    phases["device_sets_s"] = built - engine.build_s
-    nbytes = sum(s.vals.nbytes + s.images.nbytes
-                 for s in engine.device.sets.values())
-    log(f"index: preprocess_prefix {engine.build_s:.3f} s, "
-        f"{len(engine.device.sets)} device sets {phases['device_sets_s']:.3f}"
-        f" s, {nbytes} bytes on the device")
+    engine, built = kind.engine(config, data, obs)
+    phases.update(built)
 
     tiers = pow2_tiers(WARM_TIER_MAX)
     before = counts.snapshot()
     t = time.perf_counter()
-    sigs = engine.warm([list(q) for q in pool], top_k=len(pool),
-                       b_tiers=tiers)
-    # a bucket whose survivors overflow its capacity re-runs them at full
-    # capacity (one more program per signature and tier): warm that
-    # program for every signature, whether or not this seed overflows
-    reruns = {}
-    for q in pool:
-        plan = engine.plan(list(q))
-        if plan.algorithm == "device":
-            reruns.setdefault(plan.sig, plan.terms)
-    for sig, q_terms in reruns.items():
-        warm_executables([[engine.device.sets[str(x)] for x in q_terms]],
-                         b_tiers=tiers, capacity=1 << sig.ts[-1],
-                         use_pallas=engine.device.use_pallas)
-    # and serve a few of the pool's queries through the flusher
-    with engine:
-        for tk in [engine.submit(list(q)) for q in pool[:PRESERVE]]:
+    warmed = kind.warm(engine, pool, tiers)
+    # and serve a few of the pool's requests through the flusher
+    engine.start()
+    try:
+        for tk in [kind.submit(engine, q, None) for q in pool[:PRESERVE]]:
             tk.wait(DRAIN_S)
+    finally:
+        engine.stop()
     phases["warm_s"] = time.perf_counter() - t
     got = CompileCount.delta(before, counts.snapshot())
-    log(f"warm-up: {len(sigs)} signatures x tiers {list(tiers)}, and their "
-        f"re-runs; {got['programs'] - got['cache_loads']} compiled and "
-        f"{got['cache_loads']} loaded from the cache "
+    log(f"warm-up: {warmed}; {got['programs'] - got['cache_loads']} "
+        f"compiled and {got['cache_loads']} loaded from the cache "
         f"({phases['warm_s']:.3f} s)")
-    return Served(engine, lists, terms, pool, phases, counts, obs)
+    return Served(engine, kind, data, pool, phases, counts, obs)
 
 
 # ----------------------------------------------------------------------
@@ -266,8 +256,11 @@ def serve_window(served: Served, traffic: Dict, rate_qps: float,
             prof.start()
         before = served.counts.snapshot()
         t0 = time.perf_counter() + 0.05
-        replay = window.Replay(engine.submit, queries, lg.times, t0,
-                               submitters=traffic["submitters"]).start()
+        replay = window.Replay(
+            lambda q, arrival_at: served.kind.submit(engine, q, arrival_at),
+            queries, lg.times, t0, submitters=traffic["submitters"],
+            read=lambda v: (served.kind.answer(v), served.kind.route(v))
+        ).start()
         replay.join(timeout=seconds + DRAIN_S)
         replay.wait_answers(t0 + seconds + DRAIN_S)
         programs = CompileCount.delta(before, served.counts.snapshot())
@@ -368,21 +361,22 @@ class _Profiler:
 # ----------------------------------------------------------------------
 
 
-def compare_log(lists: Dict, lg: gen.Log, answers: List[object]):
-    """Compare the answer to every request of ``lg`` with the reference.
+def compare_log(kind, data, lg: gen.Log, answers: List[object]):
+    """Compare the answer to every request of ``lg`` with the kind's
+    reference over ``data``.
 
     Returns ``(counts, ok)``: the numbers compared and, per request,
     whether it was answered correctly.  The control goes through here
     too, with its own answers in the program's place."""
     used = sorted(set(lg.which.tolist()))
-    truth = {lg.pool[i]: reference.reference(lists, lg.pool[i]) for i in used}
+    truth = {lg.pool[i]: kind.reference(data, lg.pool[i]) for i in used}
     queries = [lg.pool[i] for i in lg.which]
     return reference.compare(zip(queries, answers), truth)
 
 
 def check(served: Served, win: Window):
     """Compare the program's answer to every request due in the window."""
-    return compare_log(served.lists, win.log, win.answers)
+    return compare_log(served.kind, served.data, win.log, win.answers)
 
 
 def summary(served: Served, win: Window, ok: np.ndarray) -> Dict:
@@ -396,9 +390,7 @@ def summary(served: Served, win: Window, ok: np.ndarray) -> Dict:
     quarter = [win.log.seconds * f for f in (0.25, 0.5, 0.75, 1.0)]
     out["backlog_at_quarters"] = window.backlog(due, win.resolved_s, quarter)
     snap = EXEC_COUNTERS.snapshot()
-    out["counters"] = {k: snap[k] for k in (
-        "batch_calls", "rerun_calls", "tier_flushes", "deadline_flushes",
-        "overlap_high_water", "batch_traces")}
+    out["counters"] = {k: v for k, v in snap.items() if v}
     out["routes"] = dict(collections.Counter(map(str, win.routes)))
     fill = served.obs.batch_size
     out["bucket_fill"] = {"buckets": fill.count,

@@ -3,6 +3,7 @@ reference."""
 import numpy as np
 
 from bench import gen, reference
+from bench.kinds import conj
 
 TINY = {"documents": 5000, "vocabulary": 30000, "zipf_alpha": 1.2294,
         "tokens_per_doc": 72.55, "posting_band": [16, 400]}
@@ -19,7 +20,7 @@ def test_reference_matches_brute_force():
     lists, pool = _setup()
     for q in pool:
         want = sorted(set.intersection(*(set(lists[t].tolist()) for t in q)))
-        got = reference.reference(lists, q)
+        got = conj.reference(lists, q)
         assert got.dtype == np.uint32 and got.tolist() == want
 
 
@@ -27,8 +28,8 @@ def test_control_keeps_every_true_document_and_adds_false_ones():
     lists, pool = _setup()
     extra = 0
     for q in pool:
-        truth = reference.reference(lists, q)
-        ctl = reference.control(lists, q)
+        truth = conj.reference(lists, q)
+        ctl = conj.control(lists, q)
         assert np.isin(truth, ctl).all()
         extra += len(ctl) - len(truth)
     assert extra > 0
@@ -36,7 +37,7 @@ def test_control_keeps_every_true_document_and_adds_false_ones():
 
 def test_compare_counts_each_kind_of_failure():
     lists, pool = _setup()
-    truth = {q: reference.reference(lists, q) for q in pool}
+    truth = {q: conj.reference(lists, q) for q in pool}
     q1 = next(q for q in pool if len(truth[q]))
     q0, q2, q3 = [q for q in pool if q != q1][:3]
     answers = [(q0, truth[q0]), (q1, truth[q1][:-1]), (q2, None),

@@ -110,8 +110,8 @@ def test_the_control_is_not_correct():
     sound, _ = harness.check(served, win)
     queries = [win.log.pool[i] for i in win.log.which]
     ctl, ok = harness.compare_log(
-        served.lists, win.log,
-        [reference.control(served.lists, q) for q in queries])
+        served.kind, served.data, win.log,
+        [served.kind.control(served.data, q) for q in queries])
     assert reference.verdict(sound) and not reference.verdict(ctl)
     assert ctl["mismatched"] > 0 and not ok.all()
 

@@ -56,7 +56,8 @@ def _replay(server, n=400, seconds=1.0):
     queries = [(i,) for i in range(n)]
     with window.Stamps(Ticket):
         rp = window.Replay(server.submit, queries, times,
-                           time.perf_counter() + 0.02).start()
+                           time.perf_counter() + 0.02,
+                           read=lambda v: (v.doc_ids, v.algorithm)).start()
         rp.join(10)
         rp.wait_answers(time.perf_counter() + 10)
     server.close()

@@ -102,14 +102,17 @@ class Stamps:
 class Replay:
     """One open-loop replay of a log; fills ``tickets`` and ``late_s``.
 
-    ``submit(query, arrival_at)`` is the engine's submit.  ``start``
-    launches the submitter threads; ``join`` waits for them to have
-    submitted everything.
+    ``submit(request, arrival_at=...)`` admits one request and returns its
+    ticket; ``read(value)`` turns a resolved ticket's value into the
+    answer and the route that answered it.  ``start`` launches the
+    submitter threads; ``join`` waits for them to have submitted
+    everything.
     """
 
     def __init__(self, submit: Callable, queries: Sequence, times: np.ndarray,
-                 t0: float, submitters: int = 2):
+                 t0: float, read: Callable, submitters: int = 2):
         self.submit = submit
+        self.read = read
         self.queries = queries
         self.times = times
         self.t0 = t0
@@ -128,7 +131,7 @@ class Replay:
                 if delay > 0:
                     time.sleep(delay)
                 self.late_s[j] = time.perf_counter() - due
-                self.tickets[j] = self.submit(list(self.queries[j]),
+                self.tickets[j] = self.submit(self.queries[j],
                                               arrival_at=due)
         except BaseException as exc:  # reported by join, never lost
             self.errors.append(exc)
@@ -157,8 +160,9 @@ class Replay:
 
     def outcomes(self):
         """Per request: resolution time from the window's start (NaN when
-        unresolved), the answer (doc ids, an exception, or None) and the
-        route that answered it (None unless answered)."""
+        unresolved), the answer (what ``read`` takes from the value, an
+        exception, or None) and the route that answered it (None unless
+        answered)."""
         resolved = np.full(len(self.times), np.nan)
         answers: List[object] = []
         routes: List[Optional[str]] = []
@@ -172,6 +176,7 @@ class Replay:
                 answers.append(t.error)
                 routes.append(None)
             else:
-                answers.append(t.value.doc_ids)
-                routes.append(t.value.algorithm)
+                answer, route = self.read(t.value)
+                answers.append(answer)
+                routes.append(route)
         return resolved, answers, routes
